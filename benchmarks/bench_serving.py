@@ -464,6 +464,9 @@ def main(argv=None):
         from repro.launch.hostdev import force_host_devices
 
         force_host_devices(args.shards)
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     bench(n=args.n, d=args.d, k=args.k, requests=args.requests,
           pressure=args.pressure, shards=args.shards, seed=args.seed,
           churn=args.churn, producers=args.producers,

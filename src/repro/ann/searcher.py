@@ -25,6 +25,7 @@ or :func:`make_searcher`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from collections import OrderedDict
 
@@ -204,24 +205,27 @@ class Searcher:
         return ids, dists
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "k"))
+def single_device_query(index: SCIndex, queries, *, cfg: SCConfig, k: int):
+    """The single-device query executable: ``(ids, dists, truncated,
+    candidate_count)``. The index is an argument, not a constant, so the
+    corpus never enters the HLO and every searcher over an index of the
+    same shapes shares one executable per ``(bucket, k, cfg)``."""
+    ids, dists, stats = query_with_stats(index, queries, cfg, k=k)
+    # only the O(Q) stats leave the device; the (Q, n) SC matrix stays
+    # internal to the executable
+    return ids, dists, stats["truncated"], stats["candidate_count"]
+
+
 class SingleDeviceSearcher(Searcher):
-    """Default-device execution: jitted :func:`query_with_stats` closures."""
+    """Default-device execution of :func:`single_device_query`."""
 
     def _compile(self, bucket: int, k: int, cfg: SCConfig):
-        index = self.index
-
-        @jax.jit
-        def fn(queries):
-            ids, dists, stats = query_with_stats(index, queries, cfg, k=k)
-            # only the O(Q) stats leave the device; the (Q, n) SC matrix
-            # stays internal to the executable
-            return ids, dists, stats["truncated"], stats["candidate_count"]
-
-        return fn
+        return functools.partial(single_device_query, cfg=cfg, k=k)
 
     def run_padded(self, bucket, k, cfg, queries) -> AnnBatchResult:
         ids, dists, truncated, count = jax.block_until_ready(
-            self.fn_for(bucket, k, cfg)(jnp.asarray(queries))
+            self.fn_for(bucket, k, cfg)(self.index, jnp.asarray(queries))
         )
         return AnnBatchResult(
             ids=np.asarray(ids),
@@ -259,7 +263,6 @@ class ShardedSearcher(Searcher):
                          buckets=buckets, autotune_cache=autotune_cache)
         from jax.sharding import NamedSharding
 
-        from repro.compat import make_mesh
         from repro.core.distributed import index_pspecs
 
         if mesh is None:
@@ -267,7 +270,7 @@ class ShardedSearcher(Searcher):
             shards = n_dev if shards is None else int(shards)
             if not 1 <= shards <= n_dev:
                 raise ValueError(f"shards={shards} out of range [1, {n_dev} devices]")
-            mesh = make_mesh((shards,), ("data",))
+            mesh = jax.make_mesh((shards,), ("data",))
             data_axes = ("data",)
         elif shards is not None:
             raise ValueError(
@@ -283,7 +286,8 @@ class ShardedSearcher(Searcher):
                 f"corpus size {index.n} not divisible by {self.shards} shards"
             )
         specs = index_pspecs(index, self.data_axes)
-        self._sharded_index = jax.tree.map(
+        #: the index as placed: corpus leaves sharded over the data axes
+        self.placed_index = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)) if s is not None else x,
             index,
             specs,
@@ -308,7 +312,7 @@ class ShardedSearcher(Searcher):
         from repro.core.distributed import per_shard_cap
 
         ids, dists, stats = jax.block_until_ready(
-            self.fn_for(bucket, k, cfg)(self._sharded_index, jnp.asarray(queries))
+            self.fn_for(bucket, k, cfg)(self.placed_index, jnp.asarray(queries))
         )
         shard_candidates = np.asarray(stats["shard_candidates"])
         shard_truncated = np.asarray(stats["shard_truncated"])

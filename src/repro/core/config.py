@@ -34,7 +34,9 @@ class SCConfig:
     kmeans_init: str = "random"  # 'random' | 'kmeans++'
     candidate_cap: int | None = None  # None → auto from beta & k
     seed: int = 0
-    use_kernels: bool = False  # route hot loops through Pallas kernels
+    #: Pallas l2dist centroid distances and the gather path's scscore; the
+    #: masked_full passes pick Pallas by platform regardless
+    use_kernels: bool = False
     #: candidate re-rank strategy:
     #:   'gather'      — Alg. 5 compaction into `cap` static slots + a
     #:                   (Q, cap, d) gather (may truncate beyond cap);

@@ -36,9 +36,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import axis_size, shard_map
 
 from repro.core.activation import activation_taus
 from repro.core.config import SCConfig, resolve_rerank
@@ -53,6 +52,7 @@ from repro.core.selection import (
 )
 from repro.core.taco import (
     SCIndex,
+    _project,
     _sub_slices,
     collision_constants,
     data_norms_of,
@@ -110,12 +110,6 @@ def per_shard_cap(cfg: SCConfig, n_local: int, k: int) -> int:
     return min(n_local, max(base, k))
 
 
-def _project_local(index: SCIndex, x: jax.Array) -> jax.Array:
-    if index.transform is not None:
-        return (x - index.transform.mean) @ index.transform.basis
-    return x[:, index.dim_perm]
-
-
 def make_distributed_query_with_stats(
     mesh,
     cfg: SCConfig,
@@ -169,7 +163,7 @@ def make_distributed_query_with_stats(
 
     def local_query(idx: SCIndex, queries: jax.Array):
         n_local = idx.data.shape[0]
-        pq = _project_local(idx, queries)
+        pq = _project(idx, queries)
         d1s, d2s, taus = [], [], []
         for (lo, hi), sub in zip(_sub_slices(idx.sub_dims), idx.subspaces):
             s1, _ = split_halves(hi - lo)
@@ -193,16 +187,11 @@ def make_distributed_query_with_stats(
             # so per-shard truncation is structurally impossible. For
             # fixed selection this IS the global rank cut the gather
             # branch only approximates by an even budget split (ties at
-            # the threshold level are all re-ranked).
-            from repro.kernels.masked_rerank import (
-                finalize_topk,
-                masked_rerank_stream,
-            )
-            from repro.kernels.schist import schist_stream
+            # the threshold level are all re-ranked). Both passes pick
+            # Pallas or the jnp twins by platform, as on one device.
+            from repro.kernels import ops
 
-            local_hist = schist_stream(
-                d1s, d2s, a1s, a2s, taus, n_levels=cfg.n_subspaces + 1
-            )
+            local_hist = ops.schist(d1s, d2s, a1s, a2s, taus)
             hist = jax.lax.psum(local_hist, data_axes)
             if cfg.selection == "query_aware":
                 thresh, _ = query_aware_threshold(hist, beta_n, cfg.n_subspaces)
@@ -214,11 +203,10 @@ def make_distributed_query_with_stats(
             count = jnp.sum(
                 jnp.where(levels >= thresh[:, None], local_hist, 0), axis=1
             ).astype(jnp.int32)
-            bd, bi = masked_rerank_stream(
-                d1s, d2s, a1s, a2s, taus, thresh, queries,
-                idx.data, data_norms_of(idx), k=k,
+            ids_local, dists_local = ops.masked_rerank(
+                d1s, d2s, a1s, a2s, taus, thresh,
+                idx.data, data_norms_of(idx), queries, k,
             )
-            ids_local, dists_local = finalize_topk(bd, bi, idx.data, queries, k)
             truncated = jnp.zeros_like(count, dtype=bool)
         else:
             sc = sc_scores(d1s, d2s, a1s, a2s, taus)
@@ -248,7 +236,7 @@ def make_distributed_query_with_stats(
         # globalize ids and combine across data shards
         shard_off = jnp.int32(0)
         for ax in data_axes:
-            shard_off = shard_off * axis_size(ax) + jax.lax.axis_index(ax)
+            shard_off = shard_off * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         ids_global = jnp.where(ids_local >= 0, ids_local + shard_off * n_local, -1)
         all_ids = jax.lax.all_gather(ids_global, data_axes, axis=1, tiled=True)
         all_d = jax.lax.all_gather(dists_local, data_axes, axis=1, tiled=True)
@@ -307,7 +295,7 @@ def make_distributed_cov(mesh, n_global: int, data_axes=("data",)):
 
     def local_cov(x):
         s = jnp.sum(x, axis=0)
-        outer = x.T @ x
+        outer = jnp.matmul(x.T, x, precision=jax.lax.Precision.HIGHEST)
         s = jax.lax.psum(s, data_axes)
         outer = jax.lax.psum(outer, data_axes)
         mean = s / n_global

@@ -13,6 +13,7 @@ same machinery.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -273,7 +274,8 @@ def rerank(
         dists = jnp.sum(diff * diff, axis=-1)
     else:
         q_norms = jnp.sum(queries * queries, axis=1)  # (Q,)
-        cross = jnp.einsum("qcd,qd->qc", cand_vecs, queries)
+        cross = jnp.einsum("qcd,qd->qc", cand_vecs, queries,
+                           precision=jax.lax.Precision.HIGHEST)
         dists = jnp.maximum(
             q_norms[:, None] - 2.0 * cross + jnp.take(data_norms, cand_ids), 0.0
         )
@@ -337,12 +339,14 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
     Stats parity with the gather path except ``sc`` (whose absence is the
     point) and ``candidate_count`` == ``candidate_demand`` (nothing is ever
     clamped).
+
+    Both passes pick their implementation by platform (``impl="auto"``):
+    the Pallas kernels on a TPU, their streaming jnp twins elsewhere.
     """
     from repro.kernels import ops
 
-    impl = "auto" if cfg.use_kernels else "jnp"
     d1s, d2s, a1s, a2s, taus, retrieved = _collision_inputs(index, queries, cfg)
-    hist = ops.schist(d1s, d2s, a1s, a2s, taus, impl=impl)
+    hist = ops.schist(d1s, d2s, a1s, a2s, taus)
     beta_n = float(cfg.beta * index.n)
     if cfg.selection == "query_aware":
         thresh, demand = query_aware_threshold(hist, beta_n, cfg.n_subspaces)
@@ -352,7 +356,7 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
         raise ValueError(f"unknown selection mode {cfg.selection!r}")
     ids, dists = ops.masked_rerank(
         d1s, d2s, a1s, a2s, taus, thresh,
-        index.data, data_norms_of(index), queries, k, impl=impl,
+        index.data, data_norms_of(index), queries, k,
         precision=cfg.precision,
     )
     stats = {
@@ -367,10 +371,7 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
 
 
 def make_query_fn(index: SCIndex, cfg: SCConfig, *, k: int | None = None):
-    """A jit-compiled query closure (index captured as constants)."""
-
-    @jax.jit
-    def fn(queries):
-        return query(index, queries, cfg, k=k)
-
-    return fn
+    """A jit-compiled ``fn(queries)`` over ``index``. The index is an
+    argument of the executable, not a constant baked into it."""
+    fn = jax.jit(functools.partial(query, cfg=cfg, k=k))
+    return functools.partial(fn, index)

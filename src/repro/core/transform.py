@@ -97,7 +97,9 @@ def _cov_eig(data: jax.Array):
     n = data.shape[0]
     mean = jnp.mean(data, axis=0)
     centered = data - mean
-    cov = (centered.T @ centered) / jnp.maximum(n - 1, 1)
+    cov = jnp.matmul(
+        centered.T, centered, precision=jax.lax.Precision.HIGHEST
+    ) / jnp.maximum(n - 1, 1)
     eigvals, eigvecs = jnp.linalg.eigh(cov)  # ascending
     return mean, eigvals, eigvecs
 
@@ -132,7 +134,10 @@ def apply_transform(t: SubspaceTransform, x: jax.Array) -> jax.Array:
 
     Output columns are grouped per subspace; column block j is B_j^T(x-mean).
     """
-    return (jnp.asarray(x, dtype=jnp.float32) - t.mean) @ t.basis
+    return jnp.matmul(
+        jnp.asarray(x, dtype=jnp.float32) - t.mean, t.basis,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def identity_transform(d: int, dim_order: np.ndarray | None = None):

@@ -25,7 +25,9 @@ def _assign_kernel(x_ref, c_ref, a_ref, d_ref):
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1, keepdims=True).T
     prod = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     dist = jnp.maximum(x2 + c2 - 2.0 * prod, 0.0)  # (bn, k)
     a_ref[...] = jnp.argmin(dist, axis=1).astype(jnp.int32)

@@ -25,7 +25,9 @@ def _l2dist_kernel(x_ref, y_ref, o_ref, *, n_k: int):
     x2 = jnp.sum(x * x, axis=1, keepdims=True)  # (bm, 1)
     y2 = jnp.sum(y * y, axis=1, keepdims=True).T  # (1, bn)
     prod = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     partial = x2 + y2 - 2.0 * prod
 

@@ -59,11 +59,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.schist import (
+    LANES,
     _block_sc,
     _shrink_to_divisor,
     block_sc_scores,
     cell_ids,
     collision_table,
+    query_major,
 )
 
 INF = float("inf")  # plain Python float: jnp scalars would be captured
@@ -91,14 +93,20 @@ def _partner(x, lane, stride: int):
 
 def _compare_exchange(d, i, lane, stride: int, asc):
     """One bitonic compare-exchange pass on the compound (distance, id)
-    key. ``asc`` is a per-lane bool: True where the enclosing subsequence
-    sorts ascending (partners always agree — they differ only in bit
-    ``stride``, below any direction bit)."""
+    key. ``asc`` is a per-lane int32 0/1 direction: 1 where the enclosing
+    subsequence sorts ascending (partners always agree — they differ only
+    in bit ``stride``, below any direction bit).
+
+    Lane bookkeeping stays in int32 and only float/int payloads are
+    selected: Mosaic cannot lower a bool-valued select or a bool == bool
+    compare (it truncates an i8 vector to i1)."""
     dp = _partner(d, lane, stride)
     ip = _partner(i, lane, stride)
-    is_lo = (lane & stride) == 0
-    partner_less = (dp < d) | ((dp == d) & (ip < i))
-    take = jnp.where(asc == is_lo, partner_less, ~partner_less)
+    partner_less = jnp.where((dp < d) | ((dp == d) & (ip < i)), 1, 0)
+    # the low lane of an ascending pair (and the high lane of a descending
+    # one) takes the partner when the partner is smaller
+    hi_bit = jnp.where((lane & stride) == 0, 0, 1)
+    take = (partner_less ^ asc ^ hi_bit) == 0
     return jnp.where(take, dp, d), jnp.where(take, ip, i)
 
 
@@ -108,9 +116,9 @@ def _bitonic_sort(d, i, lane, *, descending: bool = False):
     L = d.shape[1]
     size = 2
     while size <= L:
-        asc = (lane & size) == 0
+        asc = jnp.where((lane & size) == 0, 1, 0)
         if descending:
-            asc = ~asc
+            asc = 1 - asc
         stride = size // 2
         while stride:
             d, i = _compare_exchange(d, i, lane, stride, asc)
@@ -151,7 +159,7 @@ def _merge_topk(bd, bi, dist, ids_base):
     d = jnp.where(blk_less, bd_blk, bd)
     i = jnp.where(blk_less, bi_blk, bi)
     lane_k = jax.lax.broadcasted_iota(jnp.int32, (bq, kp), 1)
-    asc = jnp.ones((bq, kp), bool)
+    asc = jnp.ones((bq, kp), jnp.int32)
     stride = kp // 2
     while stride:
         d, i = _compare_exchange(d, i, lane_k, stride, asc)
@@ -179,14 +187,16 @@ def _masked_rerank_kernel(
     q = q_ref[...].astype(jnp.float32)  # (bq, d)
     x = x_ref[...].astype(jnp.float32)  # (bn, d)
     qdot = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (bq, bn)
     qn = jnp.sum(q * q, axis=1)
-    dist = jnp.maximum(qn[:, None] - 2.0 * qdot + nrm_ref[...][None, :], 0.0)
+    dist = jnp.maximum(qn[:, None] - 2.0 * qdot + nrm_ref[...], 0.0)
 
     # --- threshold + padding mask, then streaming top-k merge -------------
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
-    keep = (sc >= th_ref[...][:, None]) & (col < n_valid)
+    keep = (sc >= th_ref[:, 0:1]) & (col < n_valid)
     dist = jnp.where(keep, dist, INF)
     bd, bi = _merge_topk(bd_scr[...], bi_scr[...], dist, j * bn)
     bd_scr[...] = bd
@@ -206,7 +216,7 @@ def masked_rerank_pallas(
     d2s: jax.Array,
     a1s: jax.Array,  # (N_s, n) int32 pre-padded
     a2s: jax.Array,
-    taus: jax.Array,  # (N_s, Q)
+    taus: jax.Array,  # (N_s, Q); taus and thresh are laid out query-major
     thresh: jax.Array,  # (Q,) int32
     queries: jax.Array,  # (Q, d) pre-padded
     data: jax.Array,  # (n, d) pre-padded
@@ -231,6 +241,11 @@ def masked_rerank_pallas(
     kp = max(128, _next_pow2(k))
     n_blocks = n // bn
     grid = (q // bq, n_blocks)
+    taus = query_major(taus, jnp.float32)
+    thresh = query_major(thresh, jnp.int32)
+    # a 1-D (bn,) block does not match XLA's 1-D tiling on TPU; a (1, bn)
+    # row of a (1, n) array does
+    data_norms = data_norms.reshape(1, n)
     return pl.pallas_call(
         functools.partial(
             _masked_rerank_kernel, n_sub=n_sub, n_valid=n_valid, bn=bn,
@@ -242,11 +257,11 @@ def masked_rerank_pallas(
             pl.BlockSpec((n_sub, bq, sqrt_k), lambda i, j: (0, i, 0)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((n_sub, bq), lambda i, j: (0, i)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
+            pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bq, kp), lambda i, j: (i, 0)),
@@ -320,7 +335,7 @@ def masked_rerank_stream(
         if precision == "bf16":
             x = x.astype(jnp.bfloat16).astype(jnp.float32)
         nrm = jax.lax.dynamic_slice(norms_p, (lo,), (block,))
-        qdot = queries @ x.T
+        qdot = jnp.matmul(queries, x.T, precision=jax.lax.Precision.HIGHEST)
         dist = jnp.maximum(q_norms[:, None] - 2.0 * qdot + nrm[None, :], 0.0)
         ids = lo + jnp.arange(block, dtype=jnp.int32)
         keep = (sc >= thresh[:, None]) & (ids < n)[None, :]

@@ -15,7 +15,8 @@ def l2dist_ref(x: jax.Array, y: jax.Array) -> jax.Array:
     y = y.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     y2 = jnp.sum(y * y, axis=-1, keepdims=True).T
-    return jnp.maximum(x2 + y2 - 2.0 * (x @ y.T), 0.0)
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(x2 + y2 - 2.0 * xy, 0.0)
 
 
 def kmeans_assign_ref(x: jax.Array, c: jax.Array):
@@ -59,7 +60,8 @@ def masked_rerank_ref(d1s, d2s, a1s, a2s, taus, thresh, queries, data,
     q = queries.astype(jnp.float32)
     x = data.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=1, keepdims=True)
-    dist = jnp.maximum(qn - 2.0 * (q @ x.T) + data_norms[None, :], 0.0)
+    qx = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    dist = jnp.maximum(qn - 2.0 * qx + data_norms[None, :], 0.0)
     dist = jnp.where(sc >= thresh[:, None], dist, jnp.inf)
     neg, ids = jax.lax.top_k(-dist, k)  # stable: ties -> lowest id
     top_d = -neg

@@ -26,6 +26,14 @@ Streaming-accumulator design notes
   real assignments stay ``< sqrt_k``.
 * The level axis (N_s+1 <= ~7 buckets) is padded to one 128-lane tile;
   the wrapper slices the real levels back out.
+* TPU tiling: every block's last two dims must be multiples of (8, 128)
+  or span the whole array. The per-(subspace, query) thresholds therefore
+  enter query-major and lane-padded, ``(Q, 128)`` with subspace ``s`` in
+  lane ``s`` (:func:`query_major`), so a query block is one ``(bq, 128)``
+  tile at any bucket size.
+* Precision: the one-hot collision matmul is exact only at f32 contract
+  precision (a single bf16 pass would round the centroid distances), so it
+  runs at ``Precision.HIGHEST`` on every backend.
 """
 from __future__ import annotations
 
@@ -34,6 +42,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+#: Lane width of one TPU vreg tile; query-major per-query inputs are padded
+#: to it so their blocks tile at any query-block size.
+LANES = 128
 
 
 def _shrink_to_divisor(total: int, b: int) -> int:
@@ -46,12 +58,21 @@ def _shrink_to_divisor(total: int, b: int) -> int:
     return b
 
 
+def query_major(x: jax.Array, dtype) -> jax.Array:
+    """(N_s, Q) per-subspace rows -> (Q, LANES) query-major, zero-padded
+    lanes (subspace ``s`` in lane ``s``); a (Q,) vector becomes lane 0."""
+    x = jnp.asarray(x, dtype)
+    x = x[:, None] if x.ndim == 1 else x.T
+    assert x.shape[1] <= LANES, x.shape
+    return jnp.pad(x, ((0, 0), (0, LANES - x.shape[1])))
+
+
 def block_sc_scores(d1_ref, d2_ref, a1_ref, a2_ref, tau_ref, *, n_sub: int,
                     bq: int, bn: int) -> jax.Array:
     """In-kernel (bq, bn) SC-score tile via the one-hot-matmul collision
-    count (same math as kernels/scscore.py). Shared by the schist and
-    masked_rerank kernels so pass 1's histogram and pass 2's mask can never
-    diverge."""
+    count. Shared by the scscore, schist and masked_rerank kernels so pass
+    1's histogram and pass 2's mask can never diverge. ``tau_ref`` is the
+    query-major (bq, LANES) threshold tile."""
     sc = jnp.zeros((bq, bn), jnp.int32)
     sqrt_k = d1_ref.shape[-1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, sqrt_k), 1)
@@ -63,13 +84,17 @@ def block_sc_scores(d1_ref, d2_ref, a1_ref, a2_ref, tau_ref, *, n_sub: int,
         oh1 = (a1[:, None] == iota).astype(jnp.float32)  # (bn, sqrt_k)
         oh2 = (a2[:, None] == iota).astype(jnp.float32)
         s1 = jax.lax.dot_general(
-            oh1, d1, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            oh1, d1, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )  # (bn, bq)
         s2 = jax.lax.dot_general(
-            oh2, d2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            oh2, d2, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
-        tau = tau_ref[s]  # (bq,)
-        sc = sc + ((s1 + s2).T <= tau[:, None]).astype(jnp.int32)
+        tau = tau_ref[:, s:s + 1]  # (bq, 1)
+        sc = sc + jnp.where((s1 + s2).T <= tau, 1, 0)
     return sc
 
 
@@ -104,7 +129,7 @@ def schist_pallas(
     d2s: jax.Array,
     a1s: jax.Array,  # (N_s, n) int32 pre-padded
     a2s: jax.Array,
-    taus: jax.Array,  # (N_s, Q)
+    taus: jax.Array,  # (N_s, Q); laid out query-major here
     *,
     n_levels: int,
     n_valid: int,
@@ -122,6 +147,7 @@ def schist_pallas(
     assert n_levels <= 128, n_levels
     hw = 128
     grid = (q // bq, n // bn)  # point blocks innermost: o block revisited
+    taus = query_major(taus, jnp.float32)
     return pl.pallas_call(
         functools.partial(
             _schist_kernel, n_sub=n_sub, n_levels=n_levels, n_valid=n_valid, bn=bn
@@ -132,7 +158,7 @@ def schist_pallas(
             pl.BlockSpec((n_sub, bq, sqrt_k), lambda i, j: (0, i, 0)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((n_sub, bq), lambda i, j: (0, i)),
+            pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bq, hw), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q, hw), jnp.int32),

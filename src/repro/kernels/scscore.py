@@ -8,7 +8,9 @@ realized as a one-hot matmul — onehot(a1) (bn, sqrt_k) @ d1^T (sqrt_k, bq) —
 which is guaranteed-lowerable, MXU-aligned, and keeps the inner loop free of
 dynamic addressing. At sqrt_k <= 512 the extra MACs are noise against the MXU
 rate while the fusion removes the (N_s, Q, n) intermediates a jnp
-implementation materializes in HBM.
+implementation materializes in HBM. The tile math is
+:func:`repro.kernels.schist.block_sc_scores`, shared with the streaming
+kernels; ``taus`` enter query-major like theirs.
 
 Inputs pre-padded: Q to bq, n to bn, sqrt_k to lane multiples (padded
 distance columns are never selected because assignments stay < sqrt_k).
@@ -21,27 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.schist import LANES, block_sc_scores, query_major
+
 
 def _scscore_kernel(d1_ref, d2_ref, a1_ref, a2_ref, tau_ref, o_ref, *, n_sub: int):
-    sc = jnp.zeros(o_ref.shape, jnp.int32)  # (bq, bn)
-    sqrt_k = d1_ref.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, sqrt_k), 1)
-    for s in range(n_sub):
-        d1 = d1_ref[s].astype(jnp.float32)  # (bq, sqrt_k)
-        d2 = d2_ref[s].astype(jnp.float32)
-        a1 = a1_ref[s]  # (bn,)
-        a2 = a2_ref[s]
-        oh1 = (a1[:, None] == iota).astype(jnp.float32)  # (bn, sqrt_k)
-        oh2 = (a2[:, None] == iota).astype(jnp.float32)
-        s1 = jax.lax.dot_general(
-            oh1, d1, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bn, bq)
-        s2 = jax.lax.dot_general(
-            oh2, d2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        tau = tau_ref[s]  # (bq,)
-        sc = sc + ((s1 + s2).T <= tau[:, None]).astype(jnp.int32)
-    o_ref[...] = sc
+    bq, bn = o_ref.shape
+    o_ref[...] = block_sc_scores(d1_ref, d2_ref, a1_ref, a2_ref, tau_ref,
+                                 n_sub=n_sub, bq=bq, bn=bn)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bn", "interpret"))
@@ -50,7 +38,7 @@ def scscore_pallas(
     d2s: jax.Array,
     a1s: jax.Array,  # (N_s, n) int32
     a2s: jax.Array,
-    taus: jax.Array,  # (N_s, Q)
+    taus: jax.Array,  # (N_s, Q); laid out query-major here
     *,
     bq: int = 8,
     bn: int = 512,
@@ -60,6 +48,7 @@ def scscore_pallas(
     n = a1s.shape[1]
     assert q % bq == 0 and n % bn == 0, (d1s.shape, a1s.shape)
     grid = (q // bq, n // bn)
+    taus = query_major(taus, jnp.float32)
     return pl.pallas_call(
         functools.partial(_scscore_kernel, n_sub=n_sub),
         grid=grid,
@@ -68,7 +57,7 @@ def scscore_pallas(
             pl.BlockSpec((n_sub, bq, sqrt_k), lambda i, j: (0, i, 0)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
             pl.BlockSpec((n_sub, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((n_sub, bq), lambda i, j: (0, i)),
+            pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.int32),

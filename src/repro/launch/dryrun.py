@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.configs import ARCHS, SHAPES, get_arch, input_specs, skip_reason
 from repro.configs.shapes import resolve_arch_for_shape
 from repro.launch import sharding as SH
@@ -122,7 +121,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool):
         "attention_kind": arch.attention_kind,
     }
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt, opt_name = pick_optimizer(arch)
             meta["optimizer"] = opt_name

@@ -104,9 +104,7 @@ def main():
     )
     from repro.launch.hlo_analysis import analyze
 
-    from repro.compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jobs = {
             "query": lambda: make_distributed_query(mesh, cfg, idx_sds, n, da, query_axes=())
             .lower(idx_sds, q_sds),

@@ -1,7 +1,7 @@
 """XLA host-device forcing for CPU dev boxes.
 
-Import-safe before jax: this module must never import jax (directly or via
-repro.compat), because the whole point of :func:`force_host_devices` is to
+Import-safe before jax: this module must never import jax (directly or
+indirectly), because the whole point of :func:`force_host_devices` is to
 mutate ``XLA_FLAGS`` before jax initializes.
 """
 from __future__ import annotations

@@ -6,7 +6,8 @@ XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +15,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(multi_pod: bool) -> tuple[str, ...]:

@@ -189,6 +189,9 @@ def main(argv=None):
         from repro.launch.hostdev import force_host_devices
 
         force_host_devices(args.shards)
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
 
     import numpy as np
 

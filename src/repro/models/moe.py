@@ -40,7 +40,7 @@ def moe_apply_manual(
     `ep_axis`), computes, and the partial combine is one bf16 psum of the
     (T_local, D) output over the expert axis. Per-layer comm = one
     activation-sized all-reduce — no replicated token copies, no scatter
-    collectives. Requires an ambient mesh (repro.compat.set_mesh) and
+    collectives. Requires an ambient mesh (``jax.set_mesh``) and
     n_experts % ep_shards == 0; differentiable (psum^T = psum).
     """
     import jax as _jax
@@ -87,9 +87,7 @@ def moe_apply_manual(
         aux = _jax.lax.pmean(aux, dp_axes)
         return out.reshape(b_loc, s, d), aux
 
-    from repro.compat import shard_map as _shard_map
-
-    fn = _shard_map(
+    fn = _jax.shard_map(
         local,
         in_specs=(
             P(dp_axes, None, None),

@@ -16,9 +16,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import axis_size, shard_map
 
 from repro.models.model import ArchConfig, forward
 from repro.optim import clip_by_global_norm, compressed_psum
@@ -116,7 +115,7 @@ def make_shardmap_train_step(
         grads = compressed_psum(grads, data_axes, enabled=compress_grads)
         nshards = 1
         for ax in data_axes:
-            nshards *= axis_size(ax)
+            nshards *= jax.lax.axis_size(ax)
         grads = jax.tree.map(lambda g: g / nshards, grads)
         loss = jax.lax.pmean(loss, data_axes)
         grads, gnorm = clip_by_global_norm(grads, grad_clip)

@@ -43,13 +43,15 @@ def pairwise_sq_dists(x: jax.Array, y: jax.Array) -> jax.Array:
     """Squared Euclidean distance matrix between rows of x (M,d) and y (N,d).
 
     Uses the MXU-friendly ||x||^2 + ||y||^2 - 2 x.y^T formulation with a
-    clamp at zero to guard against negative round-off.
+    clamp at zero to guard against negative round-off. The product runs at
+    full f32 precision on every backend (a TPU's default would be one bf16
+    pass).
     """
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (M, 1)
     y2 = jnp.sum(y * y, axis=-1, keepdims=True).T  # (1, N)
-    d = x2 + y2 - 2.0 * (x @ y.T)
+    d = x2 + y2 - 2.0 * jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d, 0.0)
 
 

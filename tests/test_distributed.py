@@ -99,14 +99,14 @@ SCRIPT_MOE = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.models.moe import moe_apply, moe_apply_manual, moe_init
 
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((2, 4), ("data", "model"),
-                 axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 p = moe_init(jax.random.PRNGKey(0), 16, 32, 8)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
 ref, aux_ref = moe_apply(p, x, n_experts=8, experts_per_token=2, capacity_factor=8.0)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out, aux = jax.jit(lambda pp, xx: moe_apply_manual(
         pp, xx, n_experts=8, experts_per_token=2, capacity_factor=8.0,
         dp_axes=("data",), ep_axis="model"))(p, x)

@@ -94,17 +94,16 @@ def test_compressed_psum_matches_plain_within_tolerance():
     via shard_map on the default 1-device mesh is degenerate; test the
     numerics of the compression path with axis size 1 (exactness) and the
     quantizer error bound for the general case (above)."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro.compat import AxisType, make_mesh, shard_map
-
-    mesh = make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
     from repro.optim import compressed_psum
+
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
 
     def f(g):
         return compressed_psum({"g": g}, ("d",))["g"]
 
     g = jnp.asarray(np.random.default_rng(1).standard_normal((8, 8)), jnp.float32)
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("d"),), out_specs=P("d"),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("d"),), out_specs=P("d"),
                             check_vma=False))(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=np.abs(g).max() / 127 + 1e-6)
