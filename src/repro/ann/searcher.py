@@ -37,6 +37,7 @@ from repro.core.config import SCConfig
 from repro.core.taco import SCIndex, query_with_stats
 from repro.batching import ANN_BATCH_BUCKETS, bucket_size, pad_rows
 from repro.obs import metrics as obsm
+from repro.obs import trace as obst
 
 # Process-wide searcher metric families (repro.obs registry): executable
 # LRU behaviour and autotune warm-loads, across every searcher instance.
@@ -128,6 +129,22 @@ class Searcher:
 
     def _compile(self, bucket: int, k: int, cfg: SCConfig):
         raise NotImplementedError
+
+    def _run_executable(self, bucket: int, k: int, cfg: SCConfig, index,
+                        queries):
+        """Run the ``(bucket, k, cfg)`` executable on ``index`` and copy its
+        outputs to the host, as three stages: ``taco.searcher.dispatch``
+        (``taco.searcher.compile`` on a key new to this searcher), then
+        ``taco.searcher.device`` until the outputs are ready, then
+        ``taco.searcher.fetch``."""
+        stage = obst.default_tracer().stage
+        new_key = (bucket, k, cfg) not in self._fns
+        with stage("taco.searcher.compile" if new_key else "taco.searcher.dispatch"):
+            out = self.fn_for(bucket, k, cfg)(index, jnp.asarray(queries))
+        with stage("taco.searcher.device"):
+            jax.block_until_ready(out)
+        with stage("taco.searcher.fetch"):
+            return jax.tree.map(np.asarray, out)
 
     def run_padded(
         self, bucket: int, k: int, cfg: SCConfig, queries: np.ndarray
@@ -224,15 +241,10 @@ class SingleDeviceSearcher(Searcher):
         return functools.partial(single_device_query, cfg=cfg, k=k)
 
     def run_padded(self, bucket, k, cfg, queries) -> AnnBatchResult:
-        ids, dists, truncated, count = jax.block_until_ready(
-            self.fn_for(bucket, k, cfg)(self.index, jnp.asarray(queries))
-        )
-        return AnnBatchResult(
-            ids=np.asarray(ids),
-            dists=np.asarray(dists),
-            truncated=np.asarray(truncated),
-            candidate_count=np.asarray(count),
-        )
+        ids, dists, truncated, count = self._run_executable(
+            bucket, k, cfg, self.index, queries)
+        return AnnBatchResult(ids=ids, dists=dists, truncated=truncated,
+                              candidate_count=count)
 
 
 class ShardedSearcher(Searcher):
@@ -311,11 +323,10 @@ class ShardedSearcher(Searcher):
         from repro.core.config import resolve_rerank
         from repro.core.distributed import per_shard_cap
 
-        ids, dists, stats = jax.block_until_ready(
-            self.fn_for(bucket, k, cfg)(self.placed_index, jnp.asarray(queries))
-        )
-        shard_candidates = np.asarray(stats["shard_candidates"])
-        shard_truncated = np.asarray(stats["shard_truncated"])
+        ids, dists, stats = self._run_executable(
+            bucket, k, cfg, self.placed_index, queries)
+        shard_candidates = stats["shard_candidates"]
+        shard_truncated = stats["shard_truncated"]
         # shard_candidates is the pre-clamp per-shard DEMAND; clamp each
         # shard at its static gather cap so candidate_count keeps the
         # single-device semantics ('actually re-ranked') uniformly across
@@ -326,8 +337,8 @@ class ShardedSearcher(Searcher):
         else:
             count = shard_candidates.sum(axis=1)
         return AnnBatchResult(
-            ids=np.asarray(ids),
-            dists=np.asarray(dists),
+            ids=ids,
+            dists=dists,
             truncated=shard_truncated.any(axis=1),
             candidate_count=count.astype(np.int32),
             shard_candidates=shard_candidates,

@@ -10,13 +10,16 @@ Three modules, layered so the hot path stays cheap:
   timing through.
 * :mod:`repro.obs.trace` — per-request span trees with explicit
   cross-thread propagation, probabilistic sampling, a bounded ring, and
-  Chrome ``trace_event`` export for Perfetto / ``chrome://tracing``.
+  Chrome ``trace_event`` export for Perfetto / ``chrome://tracing``; and
+  scoped ``taco.*`` stages that also land in a JAX profiler trace, on the
+  device trace's clock.
 * :mod:`repro.obs.export` — a stdlib HTTP thread serving ``/metrics``
   (Prometheus text), ``/telemetry`` (JSON) and ``/trace`` (Chrome JSON)
   for ``serve_ann --metrics-port``.
 
 Deliberately dependency-free (stdlib only, no jax/numpy imports on the
-metrics/trace hot path) so any layer of the repo may import it.
+metrics/trace hot path; a stage imports jax when it opens) so any layer of
+the repo may import it.
 """
 from repro.obs.metrics import (
     Counter,

@@ -22,6 +22,18 @@ from an atomic counter, finished spans are single ``deque.append``
 calls — so spans may be opened and finished while holding any
 serving-stack lock without creating lock-order edges.
 
+Scoped stages on the profiler's clock: :meth:`Tracer.stage` opens and
+closes on one thread (``with tracer.stage("taco.build"): ...``). It always
+opens a ``jax.profiler.TraceAnnotation`` of its name, so a profiler
+session records it on the host plane, on the same clock as the device's
+ops; outside a session a stage costs a few microseconds of host time. It
+also records a ring span when sampled: a stage nests under the innermost
+stage open on its thread, whichever tracer opened that one, and a stage
+opened with none is a new root that takes its tracer's sampling coin.
+Stage names start with ``taco.``, so a trace reader tells the program's
+spans from anyone else's. The per-request spans that cross threads stay
+ring-only.
+
 Export: :meth:`Tracer.to_chrome` renders the ring as a Chrome
 ``trace_event`` JSON object (``{"traceEvents": [...]}`` of ``"ph": "X"``
 complete events) that loads directly in ``chrome://tracing`` or
@@ -39,7 +51,18 @@ from collections import deque
 
 from repro.obs.metrics import now
 
-__all__ = ["Span", "Tracer", "NULL_SPAN", "default_tracer", "set_default_tracer"]
+__all__ = ["Span", "Stage", "Tracer", "NULL_SPAN", "default_tracer",
+           "set_default_tracer"]
+
+class _OpenStages(threading.local):
+    """Per thread, the stages open on it, innermost last; shared by every
+    tracer, so that a stage nests under whatever stage encloses it."""
+
+    def __init__(self):
+        self.stack: list = []
+
+
+_open = _OpenStages()
 
 
 class Span:
@@ -116,6 +139,49 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class Stage:
+    """A scoped stage (see :meth:`Tracer.stage`): a profiler annotation,
+    and a ring span (``span``) when sampled, else :data:`NULL_SPAN`."""
+
+    __slots__ = ("_tracer", "name", "_attrs", "_annotation", "span")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self.name = name
+        self._attrs = attrs
+        self._annotation = None
+        self.span = NULL_SPAN
+
+    def __enter__(self) -> "Stage":
+        # imported here: repro.obs itself imports without jax
+        from jax.profiler import TraceAnnotation
+
+        stack = _open.stack
+        if stack:
+            self.span = stack[-1].span.child(self.name, **self._attrs)
+        else:
+            self.span = self._tracer.start_trace(self.name, **self._attrs)
+        self._annotation = TraceAnnotation(self.name, **self._attrs)
+        self._annotation.__enter__()
+        stack.append(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _open.stack.pop()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self.span.finish()
+
+    @property
+    def recorded(self) -> bool:
+        """Whether a profiler session or the ring records this stage."""
+        return bool(self.span) or self._annotation.is_enabled()
+
+    def annotate(self, **attrs) -> None:
+        """Add attributes to the ring span and to the profiler event."""
+        self.span.annotate(**attrs)
+        self._annotation.set_metadata(**attrs)
+
+
 class Tracer:
     """Sampling span factory + bounded ring of finished spans."""
 
@@ -144,6 +210,14 @@ class Tracer:
         self.started += 1
         tid = next(self._ids)
         return Span(self, tid, next(self._ids), None, name, attrs)
+
+    def stage(self, name: str, **attrs) -> Stage:
+        """A scoped stage named ``taco.*``, to use as a context manager on
+        one thread. It nests under the innermost stage open on this thread,
+        else it is a new root that this tracer samples."""
+        if not name.startswith("taco."):
+            raise ValueError(f"stage name {name!r} does not start with 'taco.'")
+        return Stage(self, name, attrs)
 
     def _start(self, trace_id: int, parent_id: int, name: str, attrs: dict) -> Span:
         return Span(self, trace_id, next(self._ids), parent_id, name, attrs)

@@ -85,6 +85,7 @@ collects every undelivered result as ``{request_id: AnnResult}``.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -521,7 +522,8 @@ class AnnServingEngine:
         self._executed = 0  # requests that reached the backend (not cache hits)
         self._batches = 0
         self._truncated = 0
-        self._busy_s = 0.0
+        self._t_reset = obsm.now()  # telemetry's queries_per_sec counts from here
+        self._batch_seq = itertools.count(1)  # batch numbers in traces
         self._combine_pairs = 0
         self._shard_candidates = np.zeros(self.backend.shards, np.int64)
         self._shard_truncated = np.zeros(self.backend.shards, np.int64)
@@ -675,8 +677,8 @@ class AnnServingEngine:
         now = time.monotonic()
         # root span + open queue-wait child; NULL_SPAN when unsampled (the
         # common case: every stage below is then an attribute no-op)
-        tracer = self._tracer if self._tracer is not None else obst.default_tracer()
-        span = tracer.start_trace("ann-request", k=request.k, priority=request.priority)
+        span = self._trace().start_trace("ann-request", k=request.k,
+                                         priority=request.priority)
         qspan = span.child("queue-wait")
         cache_hit: tuple[AnnFuture, AnnResult] | None = None
         with self._work:
@@ -786,39 +788,38 @@ class AnnServingEngine:
     def _drain_queue_sync(self) -> None:
         """Serve everything queued, on the calling thread (sync mode)."""
         while True:
-            resolved: list = []
-            batch = None
-            group_key = None
-            with self._work:
-                if self.result_cache_size > 0:
-                    resolved = self._serve_cache_locked()
-                if self._queue:
-                    group_key, batch = self._take_group_locked()
-            for p, r in resolved:
-                p.future._resolve(r)
-                p.qspan.finish()
-                p.span.finish(outcome="cache_hit")
-            if batch is None:
-                return
-            self._execute(group_key, batch)
+            with self._lock:
+                if not self._queue:
+                    return
+            self._serve_batch(self._take_group_locked)
 
     def _drain_loop(self) -> None:
         """Background drain worker: continuous deadline-aware micro-batch
         formation (runs as a WorkerPool service thread)."""
         while True:
-            resolved: list = []
-            batch = None
-            group_key = None
-            early = False
             with self._work:
                 while not self._queue and not self._stop.is_set():
                     self._work.wait(0.05)
                 if self._stop.is_set() and not self._queue:
                     return
+            self._serve_batch(self._form_batch_locked)
+
+    def _trace(self) -> obst.Tracer:
+        return self._tracer if self._tracer is not None else obst.default_tracer()
+
+    def _serve_batch(self, form) -> None:
+        """One batch cycle, traced as ``taco.engine.batch``: answer cache
+        hits, form a batch with ``form`` (called under the lock; returns
+        ``(group_key, batch, closed_early)``), execute it and resolve it."""
+        stage = self._trace().stage
+        with stage("taco.engine.batch") as batch_stage:
+            resolved: list = []
+            batch = None
+            with stage("taco.engine.form"), self._work:
                 if self.result_cache_size > 0:
                     resolved = self._serve_cache_locked()
                 if self._queue:
-                    group_key, batch, early = self._form_batch_locked()
+                    group_key, batch, early = form()
             for p, r in resolved:
                 p.future._resolve(r)
                 p.qspan.finish()
@@ -828,7 +829,7 @@ class AnnServingEngine:
                     with self._lock:
                         self._early_closes += 1
                     _M_BATCHES_EARLY.inc()
-                self._execute(group_key, batch)
+                self._execute(group_key, batch, batch_stage)
 
     def _take_matching_locked(self, group_key, batch: list) -> None:
         """Move queued requests matching ``group_key`` into ``batch``
@@ -861,7 +862,7 @@ class AnnServingEngine:
         group_key = self._pick_group_locked()
         batch: list = []
         self._take_matching_locked(group_key, batch)
-        return group_key, batch
+        return group_key, batch, False
 
     def _form_batch_locked(self):
         """Async batch formation: linger up to ``linger_s`` for the batch
@@ -1101,11 +1102,17 @@ class AnnServingEngine:
             )
         return k, cfg
 
-    def _execute(self, group_key, batch: list) -> None:
-        """Run one formed batch on the backend and resolve its futures."""
+    def _execute(self, group_key, batch: list, batch_stage) -> None:
+        """Run one formed batch on the backend and resolve its futures.
+        ``batch_stage`` is the cycle's ``taco.engine.batch`` stage."""
         k, cfg = group_key
-        queries = np.stack([np.asarray(p.req.query, np.float32) for p in batch])
-        bucket = bucket_size(len(batch), self.buckets)
+        stage = self._trace().stage
+        with stage("taco.engine.stage"):
+            queries = np.stack([np.asarray(p.req.query, np.float32) for p in batch])
+            bucket = bucket_size(len(batch), self.buckets)
+            padded = pad_rows(queries, bucket)
+        seq = next(self._batch_seq)
+        batch_stage.annotate(batch=seq, bucket=bucket, rows=len(batch), k=k)
         # batch formation is over for every member; the kernel stage spans
         # start now, on this (the executing) thread
         kspans = []
@@ -1114,17 +1121,24 @@ class AnnServingEngine:
                 if p.fspan is not None:
                     p.fspan.finish()
                     p.fspan = None
-                kspans.append(p.span.child("kernel", bucket=bucket, k=k))
+                kspans.append(p.span.child("kernel", batch=seq, bucket=bucket, k=k))
         with self._exec_lock:
             generation = self.index_generation
             t0 = obsm.now()
             # noqa: B001 — deliberate: _exec_lock IS the batch-vs-swap
             # serialization point; dispatch must happen under it so a
             # swap_index() can never interleave with an in-flight batch.
-            res = self.backend.run(bucket, k, cfg, pad_rows(queries, bucket))  # noqa: B001
+            res = self.backend.run(bucket, k, cfg, padded)  # noqa: B001
             dt = obsm.now() - t0
         for ks in kspans:
             ks.finish()
+        with stage("taco.engine.resolve"):
+            self._resolve_batch(group_key, batch, queries, res, generation, dt)
+
+    def _resolve_batch(self, group_key, batch: list, queries, res,
+                       generation: int, dt: float) -> None:
+        """Record one executed batch's telemetry and resolve its futures."""
+        k, _cfg = group_key
         _M_EXEC_SECONDS.observe(dt)
         _M_BATCHES.inc()
         _M_REQ_EXECUTED.inc(len(batch))
@@ -1132,7 +1146,6 @@ class AnnServingEngine:
         served: list = []
         with self._lock:
             self._batches += 1
-            self._busy_s += dt
             # a swap_index() between the run and this bookkeeping makes the
             # generation stale: results are still valid to HAND OUT (they
             # honestly describe the generation they are stamped with), but
@@ -1200,7 +1213,7 @@ class AnnServingEngine:
             self._executed = 0
             self._batches = 0
             self._truncated = 0
-            self._busy_s = 0.0
+            self._t_reset = obsm.now()
             self._combine_pairs = 0
             self._shard_candidates = np.zeros(self.backend.shards, np.int64)
             self._shard_truncated = np.zeros(self.backend.shards, np.int64)
@@ -1223,6 +1236,7 @@ class AnnServingEngine:
         if self.recall_probe_every > 0:
             self._flush_probes()  # counts must cover everything served
         with self._lock:
+            elapsed = obsm.now() - self._t_reset
             per_bucket: dict[int, int] = {}
             for (bucket, _k, _cfg), c in self.compile_counts.items():
                 per_bucket[bucket] = per_bucket.get(bucket, 0) + c
@@ -1231,7 +1245,9 @@ class AnnServingEngine:
                 "shards": self.backend.shards,
                 "requests_served": self._served,
                 "batches": self._batches,
-                "queries_per_sec": self._served / self._busy_s if self._busy_s else 0.0,
+                # answers over wall seconds since construction or the last
+                # reset_telemetry(), idle time included
+                "queries_per_sec": self._served / elapsed if elapsed > 0 else 0.0,
                 # back-compat keys, now a view over the bounded histogram
                 # (relative error <= obsm.RELATIVE_ERROR_BOUND, ~9%)
                 "latency_p50_s": self._lat_hist.percentile(50),

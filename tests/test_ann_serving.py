@@ -1,6 +1,7 @@
 """AnnServingEngine correctness: engine == direct query, padding-proof,
 jit-cache reuse, telemetry consistency."""
 import dataclasses
+import time
 
 import numpy as np
 import pytest
@@ -209,6 +210,19 @@ def test_telemetry_counters_consistent(served_index):
     assert engine.pending() == 0
     # per-request latency is the wall time of its batch
     assert all(r.latency_s > 0 for r in results)
+
+
+def test_queries_per_sec_counts_idle_wall_time(served_index):
+    """Answers over wall seconds since the last reset, idle time included:
+    a pause after serving lowers the rate."""
+    index, cfg, queries = served_index
+    engine = _fresh_engine(index, cfg, max_batch=4)
+    engine.search([AnnRequest(query=q) for q in queries[:4]])  # compiles
+    engine.reset_telemetry()
+    assert engine.telemetry()["queries_per_sec"] == 0.0
+    engine.search([AnnRequest(query=q) for q in queries[:4]])
+    time.sleep(0.2)
+    assert 0.0 < engine.telemetry()["queries_per_sec"] <= 4 / 0.2
 
 
 def test_telemetry_surfaces_lockcheck_counters(served_index):

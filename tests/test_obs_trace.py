@@ -230,3 +230,182 @@ def test_cache_hit_latency_reports_exact_zero(tiny_index):
         assert engine.telemetry()["latency_p50_s"] == 0.0
     finally:
         engine.close()
+
+
+# ------------------------------------------- scoped stages, profiler clock --
+def test_stage_names_must_start_with_taco():
+    with pytest.raises(ValueError):
+        obst.Tracer().stage("build")
+
+
+def test_stages_nest_in_the_ring_when_sampled():
+    tr = obst.Tracer(sample_rate=1.0)
+    with tr.stage("taco.outer", i=1) as outer:
+        assert outer.span
+        with tr.stage("taco.inner") as inner:
+            inner.annotate(rows=3)
+            inner.span.child("request-stage").finish()
+    by_name = {s["name"]: s for s in tr.spans()}
+    assert set(by_name) == {"taco.outer", "taco.inner", "request-stage"}
+    assert by_name["taco.outer"]["parent_id"] is None
+    assert by_name["taco.outer"]["attrs"] == {"i": 1}
+    assert by_name["taco.inner"]["parent_id"] == by_name["taco.outer"]["span_id"]
+    assert by_name["taco.inner"]["attrs"] == {"rows": 3}
+    assert by_name["request-stage"]["parent_id"] == by_name["taco.inner"]["span_id"]
+    chrome = [e["name"] for e in tr.to_chrome()["traceEvents"] if e["ph"] == "X"]
+    assert sorted(chrome) == ["request-stage", "taco.inner", "taco.outer"]
+
+
+def test_unsampled_stage_records_nothing_and_nests_nothing():
+    tr = obst.Tracer(sample_rate=0.0)
+    with tr.stage("taco.outer") as outer:
+        assert outer.span is obst.NULL_SPAN
+        with tr.stage("taco.inner") as inner:
+            assert inner.span is obst.NULL_SPAN
+            inner.annotate(rows=3)
+    assert tr.spans() == []
+    assert tr.dropped == 1  # one root coin; the inner stage followed it
+
+
+def test_stage_is_recorded_by_the_ring_or_a_profiler_session(tmp_path):
+    import jax
+
+    with obst.Tracer(sample_rate=0.0).stage("taco.off") as off:
+        assert not off.recorded
+    with obst.Tracer(sample_rate=1.0).stage("taco.ring") as ring:
+        assert ring.recorded
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obst.Tracer(sample_rate=0.0).stage("taco.profiled") as profiled:
+            assert profiled.recorded
+    finally:
+        jax.profiler.stop_trace()
+
+
+def test_stage_nests_under_another_tracers_stage():
+    """The innermost open stage decides the ring a stage lands in, whatever
+    tracer opened it: an engine's own tracer receives the searcher's
+    stages, which the process default tracer opens."""
+    mine = obst.Tracer(sample_rate=1.0)
+    other = obst.Tracer(sample_rate=0.0)
+    with mine.stage("taco.a"):
+        with other.stage("taco.b"):
+            pass
+    with other.stage("taco.c"):
+        pass
+    assert other.spans() == []
+    assert [s["name"] for s in mine.spans()] == ["taco.b", "taco.a"]
+
+
+def _host_stages(log_dir):
+    """``[(name, start_ns, end_ns, stats, line)]`` of every ``taco.*`` event
+    on a host plane of the trace under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line_no, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("taco."):
+                    out.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                                dict(ev.stats), (plane.name, line_no)))
+    return out
+
+
+def _parent_of(stage, stages):
+    """The innermost other stage on the same line that holds ``stage``."""
+    name, s, e, _stats, line = stage
+    holders = [o for o in stages if o is not stage and o[4] == line
+               and o[1] <= s and e <= o[2]]
+    return min(holders, key=lambda o: o[2] - o[1]) if holders else None
+
+
+def test_build_and_batch_stages_land_on_the_profilers_host_plane(tmp_path):
+    """Under a profiler session the build phases and the engine's batch
+    cycle are host-plane events on the device trace's clock, each nested
+    in its parent; a batch of a new bucket names its compile; with a
+    sampling tracer the same stages land in the ring too."""
+    import jax
+
+    from repro.ann import AnnIndex
+
+    rng = np.random.default_rng(5)
+    data = rng.integers(0, 30, (512, D)).astype(np.float32)
+    cfg = taco_config(n_subspaces=3, subspace_dim=8, n_clusters=64,
+                      kmeans_iters=3, alpha=0.1, beta=0.2, k=K)
+    tracer = obst.Tracer(sample_rate=1.0)
+    prev = obst.set_default_tracer(tracer)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        index = AnnIndex.build(data, cfg)
+        engine = index.engine("single", cfg=cfg, max_batch=8)
+        engine.search([AnnRequest(q, k=K) for q in data[:3]])   # bucket 4
+        engine.search([AnnRequest(q, k=K) for q in data[:7]])   # bucket 8
+        engine.search([AnnRequest(q, k=K) for q in data[3:10]])  # bucket 8 again
+        engine.close()
+    finally:
+        jax.profiler.stop_trace()
+        obst.set_default_tracer(prev)
+
+    stages = _host_stages(tmp_path)
+    names = [st[0] for st in stages]
+    parents = {"taco.build": None}
+    for phase in ("transform", "subspace", "norms"):
+        parents[f"taco.build.{phase}"] = "taco.build"
+    parents["taco.engine.batch"] = None
+    for child in ("engine.form", "engine.stage", "engine.resolve",
+                  "searcher.compile", "searcher.dispatch", "searcher.device",
+                  "searcher.fetch"):
+        parents[f"taco.{child}"] = "taco.engine.batch"
+    assert set(names) == set(parents)
+    for st in stages:
+        parent = _parent_of(st, stages)
+        assert (parent[0] if parent else None) == parents[st[0]], st
+    subs = sorted(st[3]["i"] for st in stages if st[0] == "taco.build.subspace")
+    assert subs == list(range(cfg.n_subspaces))
+    assert names.count("taco.build.transform") == names.count("taco.build.norms") == 1
+
+    batches = sorted((st for st in stages if st[0] == "taco.engine.batch"),
+                     key=lambda st: st[1])
+    assert [(b[3]["bucket"], b[3]["rows"], b[3]["k"]) for b in batches] == [
+        (4, 3, K), (8, 7, K), (8, 7, K)]
+    assert [b[3]["batch"] for b in batches] == [1, 2, 3]
+    searcher = [[c[0] for c in stages if c[0].startswith("taco.searcher.")
+                 and _parent_of(c, stages) is b] for b in batches]
+    assert searcher == [
+        ["taco.searcher.compile", "taco.searcher.device", "taco.searcher.fetch"],
+        ["taco.searcher.compile", "taco.searcher.device", "taco.searcher.fetch"],
+        ["taco.searcher.dispatch", "taco.searcher.device", "taco.searcher.fetch"],
+    ]
+
+    ring = [s["name"] for s in tracer.spans()]
+    assert sorted(n for n in ring if n.startswith("taco.")) == sorted(names)
+    chrome = {e["name"] for e in tracer.to_chrome()["traceEvents"] if e["ph"] == "X"}
+    assert set(names) <= chrome
+    # each request's kernel span joins its batch by number
+    kernels = [s for s in tracer.spans() if s["name"] == "kernel"]
+    assert sorted({s["attrs"]["batch"] for s in kernels}) == [1, 2, 3]
+
+
+def test_sharded_searcher_splits_device_and_fetch(tiny_index):
+    from repro.ann.searcher import ShardedSearcher
+
+    index, cfg, data = tiny_index
+    tracer = obst.Tracer(sample_rate=1.0)
+    searcher = ShardedSearcher(index, cfg, shards=1)
+    prev = obst.set_default_tracer(tracer)
+    try:
+        with tracer.stage("taco.test"):
+            searcher.search(data[:2])
+            searcher.search(data[:2])
+    finally:
+        obst.set_default_tracer(prev)
+    names = [s["name"] for s in tracer.spans()]
+    assert names == ["taco.searcher.compile", "taco.searcher.device",
+                     "taco.searcher.fetch", "taco.searcher.dispatch",
+                     "taco.searcher.device", "taco.searcher.fetch", "taco.test"]
