@@ -331,6 +331,7 @@ class MutableSearcher(Searcher):
         streams = []
         truncated = np.zeros((bucket,), bool)
         count = np.zeros((bucket,), np.int32)
+        rerank_blocks = None
 
         if st.base is not None and st.n_base:
             # over-fetch so that even if every tombstone outranked the k-th
@@ -350,6 +351,7 @@ class MutableSearcher(Searcher):
             truncated = np.asarray(res.truncated)
             if res.candidate_count is not None:
                 count = count + np.asarray(res.candidate_count)
+            rerank_blocks = res.rerank_blocks
 
         if st.n_delta_rows:
             rows, norms, live, ids = st.delta_padded()
@@ -369,7 +371,8 @@ class MutableSearcher(Searcher):
 
         ids, dists = _merge_topk(streams, k, bucket)
         return AnnBatchResult(
-            ids=ids, dists=dists, truncated=truncated, candidate_count=count
+            ids=ids, dists=dists, truncated=truncated, candidate_count=count,
+            rerank_blocks=rerank_blocks,
         )
 
 

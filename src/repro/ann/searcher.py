@@ -66,6 +66,8 @@ class AnnBatchResult:
     candidate_count: np.ndarray | None = None  # (B,) int32 re-ranked per query
     shard_candidates: np.ndarray | None = None  # (B, S) int32
     shard_truncated: np.ndarray | None = None  # (B, S) bool
+    #: pass 2's (grid steps that merged a point block, grid steps run)
+    rerank_blocks: tuple[int, int] | None = None
 
 
 def effective_query_params(
@@ -225,13 +227,15 @@ class Searcher:
 @functools.partial(jax.jit, static_argnames=("cfg", "k"))
 def single_device_query(index: SCIndex, queries, *, cfg: SCConfig, k: int):
     """The single-device query executable: ``(ids, dists, truncated,
-    candidate_count)``. The index is an argument, not a constant, so the
-    corpus never enters the HLO and every searcher over an index of the
-    same shapes shares one executable per ``(bucket, k, cfg)``."""
+    candidate_count, rerank_blocks)``, the last None where pass 2 reports
+    no counts. The index is an argument, not a constant, so the corpus
+    never enters the HLO and every searcher over an index of the same
+    shapes shares one executable per ``(bucket, k, cfg)``."""
     ids, dists, stats = query_with_stats(index, queries, cfg, k=k)
     # only the O(Q) stats leave the device; the (Q, n) SC matrix stays
     # internal to the executable
-    return ids, dists, stats["truncated"], stats["candidate_count"]
+    return (ids, dists, stats["truncated"], stats["candidate_count"],
+            stats.get("rerank_blocks"))
 
 
 class SingleDeviceSearcher(Searcher):
@@ -241,10 +245,12 @@ class SingleDeviceSearcher(Searcher):
         return functools.partial(single_device_query, cfg=cfg, k=k)
 
     def run_padded(self, bucket, k, cfg, queries) -> AnnBatchResult:
-        ids, dists, truncated, count = self._run_executable(
+        ids, dists, truncated, count, blocks = self._run_executable(
             bucket, k, cfg, self.index, queries)
-        return AnnBatchResult(ids=ids, dists=dists, truncated=truncated,
-                              candidate_count=count)
+        return AnnBatchResult(
+            ids=ids, dists=dists, truncated=truncated, candidate_count=count,
+            rerank_blocks=None if blocks is None else
+            (int(blocks[0]), int(blocks[1])))
 
 
 class ShardedSearcher(Searcher):

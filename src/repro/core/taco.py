@@ -360,7 +360,8 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
 
     Stats parity with the gather path except ``sc`` (whose absence is the
     point) and ``candidate_count`` == ``candidate_demand`` (nothing is ever
-    clamped).
+    clamped). The Pallas pass 2 adds ``rerank_blocks``, its int32
+    ``(merged, run)`` grid-step counts (see ``kernels.masked_rerank``).
 
     Both passes pick their implementation by platform (``impl="auto"``):
     the Pallas kernels on a TPU, their streaming jnp twins elsewhere.
@@ -376,10 +377,10 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
         thresh, demand = fixed_threshold_from_hist(hist, beta_n, index.n)
     else:
         raise ValueError(f"unknown selection mode {cfg.selection!r}")
-    ids, dists = ops.masked_rerank(
+    ids, dists, rerank_blocks = ops.masked_rerank(
         d1s, d2s, a1s, a2s, taus, thresh,
         index.data, data_norms_of(index), queries, k,
-        precision=cfg.precision,
+        precision=cfg.precision, counts=True,
     )
     stats = {
         "taus": taus,
@@ -389,6 +390,8 @@ def _query_masked_full(index: SCIndex, queries: jax.Array, cfg: SCConfig, k: int
         "candidate_demand": demand,
         "truncated": jnp.zeros(queries.shape[0], bool),
     }
+    if rerank_blocks is not None:
+        stats["rerank_blocks"] = rerank_blocks
     return ids, dists, stats
 
 

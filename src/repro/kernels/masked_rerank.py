@@ -16,7 +16,8 @@ Per (query block, point block) grid step the kernel
      streaming accumulator: bitonic partial sort of the block, then one
      sorted-run merge against the state — O(log^2 bn + log kp) vectorized
      compare-exchange passes instead of the old k rounds of extract-min,
-     which scaled linearly with k).
+     which scaled linearly with k) — but only when some distance of the
+     block beats the state's k-th best (the skip rule below).
 
 No candidate set is ever materialized and there is no static candidate
 cap, so truncation is structurally impossible: every point at or above
@@ -34,8 +35,6 @@ Streaming-accumulator design notes
   state; padded query rows produce garbage that the wrapper slices off;
   padded sqrt_k distance columns are never selected (assignments stay
   < sqrt_k); the feature dim is zero-padded (exact for dot products).
-  Scratch slots >= k hold +inf and are excluded from the worst-slot
-  search, so the state can never grow beyond k real entries.
 * Tie handling: every compare-exchange uses the compound (distance, id)
   key, so distance ties resolve to the lowest point id. Because point
   blocks stream in ascending-id order, this is exactly the old
@@ -43,11 +42,27 @@ Streaming-accumulator design notes
   id), and the same rule as the gather path's stable top_k over
   index-ordered candidates. The wrapper canonicalizes the final slot
   order (distance-major, id-minor) for bitwise-stable results.
-* State layout: the (bq, kp) scratch is kept fully sorted ascending by
+* State layout: the (bq, kp) scratch is kept sorted ascending by
   (distance, id). Unfilled slots hold (+inf, -1); masked/padded points
   carry (+inf, real id), which the compound order places AFTER every
   (+inf, -1), so they can never displace an empty slot — the first k
   lanes are always the k best (or (+inf, -1) when fewer points pass).
+  The merge keeps kp entries, but only lanes < k are ever read:
+  ``finalize_topk`` slices ``[:, :k]``. Lanes k..kp-1 hold real entries
+  of earlier blocks (or (+inf, -1)), each no smaller than lane k-1, and
+  not necessarily the (k+1)-th..kp-th best.
+* Skip rule: a grid step merges its block only when some masked distance
+  is strictly below the state's k-th best, ``bd[:, k-1]``; otherwise the
+  sort and merge are skipped and the state is left as it was. That returns
+  the same first k lanes: the k smallest of state ∪ block are the k
+  smallest of state[:k] ∪ block, and a block entry can only displace the
+  k-th entry with a strictly smaller distance, since blocks stream in
+  ascending id and an equal distance resolves to the incumbent's lower
+  id. Masked and padded entries are +inf and never pass ``< kth``, even
+  while kth is still +inf. Corpus order is random with respect to a
+  query, so once the state is full only a few percent of blocks hold any
+  distance below the k-th best. Each query block counts the steps that
+  merged; the count is the kernel's third output.
 """
 from __future__ import annotations
 
@@ -169,8 +184,8 @@ def _merge_topk(bd, bi, dist, ids_base):
 
 def _masked_rerank_kernel(
     d1_ref, d2_ref, a1_ref, a2_ref, tau_ref, th_ref, q_ref, x_ref, nrm_ref,
-    od_ref, oi_ref, bd_scr, bi_scr, *, n_sub: int, n_valid: int,
-    bn: int, n_blocks: int
+    od_ref, oi_ref, om_ref, bd_scr, bi_scr, nm_scr, *, k: int, n_sub: int,
+    n_valid: int, bn: int, n_blocks: int
 ):
     j = pl.program_id(1)
 
@@ -178,6 +193,7 @@ def _masked_rerank_kernel(
     def _init():
         bd_scr[...] = jnp.full_like(bd_scr, INF)
         bi_scr[...] = jnp.full_like(bi_scr, -1)
+        nm_scr[...] = jnp.zeros_like(nm_scr)
 
     bq = od_ref.shape[0]
     sc = block_sc_scores(d1_ref, d2_ref, a1_ref, a2_ref, tau_ref,
@@ -198,14 +214,23 @@ def _masked_rerank_kernel(
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
     keep = (sc >= th_ref[:, 0:1]) & (col < n_valid)
     dist = jnp.where(keep, dist, INF)
-    bd, bi = _merge_topk(bd_scr[...], bi_scr[...], dist, j * bn)
-    bd_scr[...] = bd
-    bi_scr[...] = bi
+    # skip rule (module notes): merge only a block that can change the
+    # first k lanes
+    kth = bd_scr[:, k - 1:k]
+    beats_kth = jnp.max(jnp.where(dist < kth, 1, 0)) > 0
+
+    @pl.when(beats_kth)
+    def _merge():
+        bd, bi = _merge_topk(bd_scr[...], bi_scr[...], dist, j * bn)
+        bd_scr[...] = bd
+        bi_scr[...] = bi
+        nm_scr[...] += 1
 
     @pl.when(j == n_blocks - 1)
     def _finish():
         od_ref[...] = bd_scr[...]
         oi_ref[...] = bi_scr[...]
+        om_ref[...] = nm_scr[...]
 
 
 @functools.partial(
@@ -228,9 +253,13 @@ def masked_rerank_pallas(
     bn: int = 512,
     interpret: bool = False,
 ):
-    """Per-query top-k state: ((Q, kp) dists f32, (Q, kp) ids i32), sorted
-    ascending by (distance, id); the first k lanes are the top-k (id -1 /
-    +inf when fewer than k points pass the threshold). ``bq``/``bn`` that
+    """Per-query top-k state and merge count: ((Q, kp) dists f32, (Q, kp)
+    ids i32, (Q, 128) merged i32). The first k lanes of the state are the
+    top-k, ascending by (distance, id) (id -1 / +inf when fewer than k
+    points pass the threshold); lanes >= k are not part of the answer.
+    Every entry of a query block's rows of ``merged`` holds the number of
+    point blocks that block merged (the skip rule's misses did not), so
+    ``merged[::bq, 0]`` is one count per grid row. ``bq``/``bn`` that
     do not divide Q/n are auto-shrunk to the largest divisor instead of
     crashing (direct callers with odd shapes; the padded ``ops`` wrappers
     always pass divisible shapes)."""
@@ -248,8 +277,8 @@ def masked_rerank_pallas(
     data_norms = data_norms.reshape(1, n)
     return pl.pallas_call(
         functools.partial(
-            _masked_rerank_kernel, n_sub=n_sub, n_valid=n_valid, bn=bn,
-            n_blocks=n_blocks,
+            _masked_rerank_kernel, k=k, n_sub=n_sub, n_valid=n_valid,
+            bn=bn, n_blocks=n_blocks,
         ),
         grid=grid,
         in_specs=[
@@ -266,14 +295,17 @@ def masked_rerank_pallas(
         out_specs=[
             pl.BlockSpec((bq, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((q, kp), jnp.float32),
             jax.ShapeDtypeStruct((q, kp), jnp.int32),
+            jax.ShapeDtypeStruct((q, LANES), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, kp), jnp.float32),
             pltpu.VMEM((bq, kp), jnp.int32),
+            pltpu.VMEM((bq, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(d1s, d2s, a1s, a2s, taus, thresh, queries, data, data_norms)

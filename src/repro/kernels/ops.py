@@ -147,10 +147,14 @@ def schist(d1s, d2s, a1s, a2s, taus, impl: str = "auto",
 def masked_rerank(d1s, d2s, a1s, a2s, taus, thresh, data, data_norms,
                   queries, k: int, impl: str = "auto", block: int = 4096,
                   blocks: tuple[int, int] | None = None,
-                  precision: str = "f32"):
+                  precision: str = "f32", counts: bool = False):
     """Streaming masked full-matmul re-rank: ((Q, k) ids i32, (Q, k) exact
     sq dists f32), no candidate cap and no (Q, n)/(Q, cap, d) intermediate;
     see kernels/masked_rerank.py.
+
+    ``counts=True`` appends the Pallas kernel's grid-step counts, an int32
+    ``(merged, run)`` pair: the steps that merged their point block into
+    the running top-k, and all steps. The jnp path appends None.
 
     ``blocks`` overrides the Pallas (bq, bn) tile sizes (autotune cache
     consulted when None). ``precision="bf16"`` streams bfloat16 query/data
@@ -165,7 +169,8 @@ def masked_rerank(d1s, d2s, a1s, a2s, taus, thresh, data, data_norms,
             d1s, d2s, a1s, a2s, taus, thresh, queries, data, data_norms,
             k=k, block=block, precision=precision,
         )
-        return finalize_topk(bd, bi, data, queries, k)
+        ids, dists = finalize_topk(bd, bi, data, queries, k)
+        return (ids, dists, None) if counts else (ids, dists)
     _n_sub, q, _sk = d1s.shape
     n = data.shape[0]
     bq, bn = blocks or autotune.get_blocks("masked_rerank", precision,
@@ -186,8 +191,13 @@ def masked_rerank(d1s, d2s, a1s, a2s, taus, thresh, data, data_norms,
     if precision == "bf16":
         qp = qp.astype(jnp.bfloat16)
         xp = xp.astype(jnp.bfloat16)
-    bd, bi = masked_rerank_pallas(
+    bd, bi, merged = masked_rerank_pallas(
         d1p, d2p, a1p, a2p, taup, thp, qp, xp, nrmp,
         k=k, n_valid=n, bq=bq, bn=bn, interpret=interpret,
     )
-    return finalize_topk(bd[:q], bi[:q], data, queries, k)
+    ids, dists = finalize_topk(bd[:q], bi[:q], data, queries, k)
+    if not counts:
+        return ids, dists
+    row_counts = merged[::bq, 0]
+    steps = row_counts.shape[0] * (xp.shape[0] // bn)
+    return ids, dists, jnp.stack([jnp.sum(row_counts), jnp.int32(steps)])

@@ -125,6 +125,14 @@ _M_BATCHES_EARLY = obsm.counter(
     "taco_engine_batches_closed_early_total",
     "Batches a member's deadline closed before linger/full",
 )
+_M_RERANK_MERGED = obsm.counter(
+    "taco_rerank_blocks_merged_total",
+    "Pass 2 grid steps that merged their point block into the top-k",
+)
+_M_RERANK_BLOCKS = obsm.counter(
+    "taco_rerank_blocks_total",
+    "Pass 2 grid steps run (query blocks x point blocks, every batch)",
+)
 _M_DEGRADED = obsm.counter(
     "taco_engine_degraded_admissions_total",
     "Requests admitted with a degraded (scaled-down) re-rank budget",
@@ -1142,6 +1150,10 @@ class AnnServingEngine:
         _M_EXEC_SECONDS.observe(dt)
         _M_BATCHES.inc()
         _M_REQ_EXECUTED.inc(len(batch))
+        if res.rerank_blocks is not None:
+            merged, steps = res.rerank_blocks
+            _M_RERANK_MERGED.inc(merged)
+            _M_RERANK_BLOCKS.inc(steps)
         now = time.monotonic()
         served: list = []
         with self._lock:

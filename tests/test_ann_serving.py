@@ -309,3 +309,38 @@ def test_recall_probes_report_live_recall(served_index, small_dataset):
     assert engine.telemetry()["recall_probe_count"] == len(queries) // 2
     engine.reset_telemetry()
     assert engine.telemetry()["recall_probe_count"] == 0
+
+
+def test_engine_counts_pass2_blocks_from_its_backend(served_index):
+    """The engine adds a backend's pass 2 grid-step counts to
+    ``taco_rerank_blocks_merged_total`` / ``taco_rerank_blocks_total``, and
+    nothing where the backend reports none."""
+    from repro.ann.searcher import AnnBatchResult, Searcher
+    from repro.obs import default_registry
+
+    index, cfg, queries = served_index
+
+    class Stub(Searcher):
+        def __init__(self, blocks):
+            super().__init__(index, cfg)
+            self.blocks = blocks
+
+        def run_padded(self, bucket, k, cfg, queries):
+            return AnnBatchResult(
+                ids=np.zeros((bucket, k), np.int32),
+                dists=np.zeros((bucket, k), np.float32),
+                truncated=np.zeros((bucket,), bool),
+                rerank_blocks=self.blocks)
+
+    names = ("taco_rerank_blocks_merged_total", "taco_rerank_blocks_total")
+
+    def counts():
+        snap = default_registry().snapshot()
+        return np.array([snap.get(n, 0.0) for n in names])
+
+    for blocks, want in (((3, 40), [6, 80]), (None, [0, 0])):
+        engine = _fresh_engine(index, cfg, max_batch=4, backend=Stub(blocks))
+        before = counts()
+        engine.search([AnnRequest(query=q) for q in queries[:8]])
+        np.testing.assert_array_equal(counts() - before, want)
+        assert engine.telemetry()["batches"] == 2

@@ -72,22 +72,134 @@ def test_schist_stream_property(n_sub, q, sqrt_k, n, seed):
 
 
 # ----------------------------------------------------- masked_rerank kernel
-@pytest.mark.parametrize("n_sub,q,sqrt_k,n,k", [
-    (2, 3, 5, 50, 5),
-    (6, 8, 16, 512, 10),   # block-divisible
-    (4, 5, 32, 1030, 17),  # padded n, odd k
-    (3, 1, 8, 40, 40),     # k == n
+def _ordered_case(rng, layout, q, n, *, n_sub=2, sqrt_k=4, d=16, bn=128):
+    """Collision inputs and integer rows whose distance to every query
+    follows ``layout`` in id order, so the skip rule's cases can be built:
+
+    * ``ascending`` / ``descending``: distance non-decreasing / non-increasing
+      in id; ``random``: a permutation of the ascending rows;
+    * ``ties``: a group of 8 rows at one small distance straddling the end
+      of the first ``bn`` block, every other row far;
+    * ``few``: random order, and only 5 rows can pass a threshold above 0.
+
+    Cell (0, 0) alone collides (0 <= tau 1; every other sum >= 4), so a row
+    scores ``n_sub`` when its cells are (0, 0) and 0 otherwise. Thresholds
+    are 0 (every row passes), ``n_sub`` (the marked rows) or ``n_sub + 1``
+    (none). Every value is exact in bfloat16, every distance in float32."""
+    i = np.arange(n)
+    r = i * 256 // n  # 0..255, non-decreasing
+    marked = rng.random(n) < 0.7
+    if layout == "descending":
+        r = r[::-1]
+    elif layout in ("random", "few"):
+        r = rng.permutation(r)
+    elif layout == "ties":
+        r = 200 + i % 50
+        r[bn - 4:bn + 4] = 3
+    if layout == "few":
+        marked = np.zeros(n, bool)
+        marked[rng.choice(n, 5, replace=False)] = True
+    data = np.zeros((n, d), np.float32)
+    data[:, 0] = r
+    queries = np.zeros((q, d), np.float32)
+    queries[:, 0] = -rng.integers(1, 4, q)
+    queries[:, d // 2:] = rng.integers(-8, 9, (q, d - d // 2))
+    d1s = np.full((n_sub, q, sqrt_k), 4.0, np.float32)
+    d1s[:, :, 0] = 0.0
+    cells = np.where(marked, 0, 1).astype(np.int32)
+    a1s = np.broadcast_to(cells, (n_sub, n))
+    a2s = np.zeros((n_sub, n), np.int32)
+    taus = np.ones((n_sub, q), np.float32)
+    thresh = np.where(rng.random(q) < 0.5, 0, n_sub).astype(np.int32)
+    if layout == "few":
+        thresh[:] = n_sub
+        thresh[0] = n_sub + 1
+    data, queries = jnp.asarray(data), jnp.asarray(queries)
+    return (jnp.asarray(d1s), jnp.asarray(d1s), jnp.asarray(a1s),
+            jnp.asarray(a2s), jnp.asarray(taus), jnp.asarray(thresh), data,
+            jnp.sum(data * data, axis=1), queries)
+
+
+# "random" cases draw every input (default blocks); the others stream a few
+# thousand rows through (8, 128) blocks in the distance order they name
+@pytest.mark.parametrize("n_sub,q,sqrt_k,n,k,layout,precision", [
+    pytest.param(2, 3, 5, 50, 5, "random", "f32", id="2-3-5-50-5"),
+    pytest.param(6, 8, 16, 512, 10, "random", "f32",  # block-divisible
+                 id="6-8-16-512-10"),
+    pytest.param(4, 5, 32, 1030, 17, "random", "f32",  # padded n, odd k
+                 id="4-5-32-1030-17"),
+    pytest.param(3, 1, 8, 40, 40, "random", "f32",  # k == n
+                 id="3-1-8-40-40"),
+    # only the first blocks merge
+    pytest.param(2, 9, 4, 3000, 10, "ascending", "f32", id="ascending-k10"),
+    pytest.param(2, 9, 4, 3000, 100, "ascending", "bf16",
+                 id="ascending-k100-bf16"),
+    # every block merges
+    pytest.param(2, 9, 4, 3000, 10, "descending", "f32", id="descending-k10"),
+    pytest.param(2, 9, 4, 3000, 1, "descending", "bf16",
+                 id="descending-k1-bf16"),
+    # equal distances at the k-th slot across a block boundary: the second
+    # block's equals are skipped (k 3) or merged behind lower ids (k 6)
+    pytest.param(2, 9, 4, 3000, 3, "ties", "f32", id="ties-k3"),
+    pytest.param(2, 9, 4, 3000, 6, "ties", "bf16", id="ties-k6-bf16"),
+    # fewer than k rows pass: (+inf, -1) slots stay
+    pytest.param(2, 9, 4, 3000, 10, "few", "f32", id="few-k10"),
+    pytest.param(2, 9, 4, 3000, 100, "few", "bf16", id="few-k100-bf16"),
+    pytest.param(2, 9, 4, 3000, 1, "random", "bf16", id="random-k1-bf16"),
 ])
-def test_masked_rerank_pallas_matches_ref(n_sub, q, sqrt_k, n, k):
-    rng = np.random.default_rng(n_sub * 1000 + n)
-    d1s, d2s, a1s, a2s, taus, thresh, data, norms, queries = _case(
-        rng, n_sub, q, sqrt_k, n)
+def test_masked_rerank_pallas_matches_ref(n_sub, q, sqrt_k, n, k, layout,
+                                          precision):
+    rng = np.random.default_rng(n_sub * 1000 + n + k)
+    if layout == "random" and precision == "f32":
+        inputs = _case(rng, n_sub, q, sqrt_k, n)
+        blocks = None
+    else:
+        inputs = _ordered_case(rng, layout, q, n, n_sub=n_sub, sqrt_k=sqrt_k)
+        blocks = (8, 128)
+    d1s, d2s, a1s, a2s, taus, thresh, data, norms, queries = inputs
     gi, gd = ops.masked_rerank(d1s, d2s, a1s, a2s, taus, thresh, data, norms,
-                               queries, k, impl="pallas")
+                               queries, k, impl="pallas", blocks=blocks,
+                               precision=precision)
     wi, wd = ref.masked_rerank_ref(d1s, d2s, a1s, a2s, taus, thresh, queries,
                                    data, norms, k)
+    ji, jd = ops.masked_rerank(d1s, d2s, a1s, a2s, taus, thresh, data, norms,
+                               queries, k, impl="jnp", precision=precision)
+    for oi, od in ((wi, wd), (ji, jd)):
+        np.testing.assert_array_equal(np.asarray(gi), np.asarray(oi))
+        np.testing.assert_array_equal(np.asarray(gd).view(np.int32),
+                                      np.asarray(od).view(np.int32))
+    if layout == "few":
+        assert (np.asarray(gi) == -1).any(), "no empty slot exercised"
+
+
+@pytest.mark.parametrize("layout,thresh,merged", [
+    ("descending", 0, 16),   # every (query block, point block) step
+    ("ascending", 0, 2),     # the first point block of each query block
+    ("descending", 3, 0),    # every row masked
+])
+def test_rerank_merge_counts(layout, thresh, merged):
+    """The kernel's count of grid steps that merged, on 16 queries (two
+    query blocks) over 1024 rows at strictly monotone distances (eight
+    point blocks): 16 steps in all."""
+    n, q, n_sub = 1024, 16, 2
+    rng = np.random.default_rng(5)
+    d1s, d2s, a1s, a2s, taus, _th, _x, _nrm, queries = _ordered_case(
+        rng, "ascending", q, n, n_sub=n_sub)
+    r = np.arange(n) if layout == "ascending" else np.arange(n)[::-1]
+    data = jnp.zeros((n, 16), jnp.float32).at[:, 0].set(r.astype(np.float32))
+    norms = jnp.sum(data * data, axis=1)
+    thresh = jnp.full((q,), thresh, jnp.int32)
+    args = (d1s, d2s, jnp.zeros_like(a1s), a2s, taus, thresh, data, norms,
+            queries, 10)
+    gi, gd, counts = ops.masked_rerank(*args, impl="pallas", blocks=(8, 128),
+                                       counts=True)
+    np.testing.assert_array_equal(np.asarray(counts), [merged, 16])
+    wi, wd = ref.masked_rerank_ref(d1s, d2s, jnp.zeros_like(a1s), a2s, taus,
+                                   thresh, queries, data, norms, 10)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_array_equal(np.asarray(gd), np.asarray(wd))
+    # the jnp twin runs no grid and counts nothing
+    assert ops.masked_rerank(*args, impl="jnp", counts=True)[2] is None
 
 
 @settings(max_examples=10, deadline=None)

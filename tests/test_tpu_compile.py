@@ -85,8 +85,16 @@ def test_scscore_compiles(one_chip, tpu_kernels):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("precision", ["f32", "bf16"])
-def test_masked_rerank_compiles(one_chip, tpu_kernels, precision):
+@pytest.mark.parametrize("precision,k", [
+    pytest.param("f32", 10, id="f32"),
+    pytest.param("bf16", 10, id="bf16"),
+    # k 100: the skip rule reads lane 99 of the (bq, 128) state
+    pytest.param("f32", 100, id="f32-k100"),
+    pytest.param("bf16", 100, id="bf16-k100"),
+])
+def test_masked_rerank_compiles(one_chip, tpu_kernels, precision, k):
+    """With the skip rule's scalar branch (a full vector reduction) and the
+    merge count, as the query executable runs it."""
     args = _collision_inputs(one_chip) + (
         _sds(one_chip, (Q,), jnp.int32),
         _sds(one_chip, (N, D), jnp.float32),
@@ -94,10 +102,13 @@ def test_masked_rerank_compiles(one_chip, tpu_kernels, precision):
         _sds(one_chip, (Q, D), jnp.float32),
     )
     compiled = _compile(
-        lambda *a: ops.masked_rerank(*a, 10, impl="pallas",
-                                     precision=precision),
+        lambda *a: ops.masked_rerank(*a, k, impl="pallas",
+                                     precision=precision, counts=True),
         *args)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the benchmark's roofline reader finds pass 2 by this name
+    assert "%masked_rerank_pallas" in text
 
 
 def test_l2dist_compiles(one_chip, tpu_kernels):
