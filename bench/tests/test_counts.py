@@ -1,9 +1,10 @@
 """The roofline work counts against hand arithmetic, and the peak table."""
+import json
 import types
 
 import pytest
 
-import bench_tiny  # noqa: F401  (import paths)
+import bench_tiny
 from tacobench import counts, peaks, spec
 
 V5E = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -36,13 +37,34 @@ def test_config_files_match_the_counted_shapes():
     assert (data["n"], data["d"]) == (10_000_000, 96)
 
 
+def assert_own_metrics(bench, cell_name):
+    """The rule by which a cell gets its metrics: the end-to-end metrics
+    whose ``workloads`` name it or that have none (``setup_s`` among
+    them, and one more), none named for another configuration, and at
+    least one per-layer metric, each moving one of its own end-to-end
+    metrics."""
+    config = {w["name"]: w["config"] for w in bench["workloads"]}[cell_name]
+    others = {c["name"] for c in bench["configs"]} - {config}
+    cell = spec.cell(cell_name, bench)
+    e2e = [m["name"] for m in cell.end_to_end]
+    assert e2e == [m["name"] for m in bench["end_to_end"]
+                   if cell_name in m.get("workloads", [cell_name])]
+    assert "setup_s" in e2e and len(e2e) > 1
+    for m in cell.end_to_end + cell.per_layer:
+        assert m["name"].rsplit(".", 1)[-1] not in others, m["name"]
+    assert cell.per_layer
+    for m in cell.per_layer:
+        assert m["moves"] in e2e, m["name"]
+    return cell
+
+
+@pytest.mark.parametrize(
+    "cell_name", [w["name"] for w in spec.load_benchmark()["workloads"]])
+def test_every_cell_gets_its_own_configurations_metrics(cell_name):
+    assert_own_metrics(spec.load_benchmark(), cell_name)
+
+
 def test_a_cell_gets_its_own_configurations_metrics():
-    cell = spec.cell("deep10m.bulk")
-    assert sorted(m["name"] for m in cell.end_to_end) == [
-        "build_s.deep10m", "qps.deep10m", "recall_at_10.deep10m", "setup_s"]
-    assert sorted(m["name"] for m in cell.per_layer) == [
-        "device_idle.deep10m", "hbm_peak_gb.deep10m",
-        "masked_rerank_roofline.deep10m", "schist_roofline.deep10m"]
     bench = {"workloads": [{"name": "x.bulk", "config": "deep10m",
                             "traffic": "deep10m.bulk", "chips": 1}],
              "configs": spec.load_benchmark()["configs"],
@@ -54,6 +76,19 @@ def test_a_cell_gets_its_own_configurations_metrics():
     other = spec.cell("x.bulk", bench)
     assert [m["name"] for m in other.end_to_end] == ["qps.x", "setup_s"]
     assert [m["name"] for m in other.per_layer] == ["a"]
+    # a second traffic mix on the same configuration, with a metric of
+    # the configuration's own that only it reports, leaves x.bulk as it was
+    bench["workloads"].append({"name": "x.poisson", "config": "deep10m",
+                               "traffic": "deep10m.bulk", "chips": 1})
+    bench["end_to_end"].append(
+        {"name": "p99_ms.deep10m", "workloads": ["x.poisson"]})
+    bench["per_layer"].append({"name": "d.deep10m", "moves": "p99_ms.deep10m"})
+    poisson = assert_own_metrics(bench, "x.poisson")
+    assert [m["name"] for m in poisson.end_to_end] == ["setup_s", "p99_ms.deep10m"]
+    assert [m["name"] for m in poisson.per_layer] == ["d.deep10m"]
+    bulk = assert_own_metrics(bench, "x.bulk")
+    assert [m["name"] for m in bulk.end_to_end] == ["qps.x", "setup_s"]
+    assert [m["name"] for m in bulk.per_layer] == ["a"]
 
 
 def test_peak_table():
@@ -84,6 +119,40 @@ def test_roofline_readers_hit_100_at_the_least_time():
     # nothing to read: no launch, no trace
     assert rerank(_run(0.0, 0)) is None
     assert schist(types.SimpleNamespace(trace=None, peak=V5E)) is None
+
+
+def test_rerank_merge_share_reads_the_program_counters():
+    read = spec.reader("rerank_merge_share.deep10m")
+
+    def run(**counters):
+        return types.SimpleNamespace(counters=counters)
+
+    assert read(run(taco_rerank_blocks_merged_total=3750.0,
+                    taco_rerank_blocks_total=156_256.0)) == pytest.approx(
+        100 * 3750 / 156_256)
+    assert read(run(taco_rerank_blocks_merged_total=0.0,
+                    taco_rerank_blocks_total=64.0)) == 0.0
+    # nothing to read: pass 2 counted no step (the jnp path), or no counter
+    assert read(run(taco_rerank_blocks_merged_total=0.0,
+                    taco_rerank_blocks_total=0.0)) is None
+    assert read(run()) is None
+
+
+@pytest.mark.parametrize(
+    "config", [c["name"] for c in spec.load_benchmark()["configs"]])
+def test_every_configuration_has_a_tiny_cut(config):
+    cut = bench_tiny.tiny(config)
+    assert set(cut) == {"data", "taco", "engine", "recall_at_10_min", "why"}
+    assert {"n", "d", "n_queries", "n_probes"} <= set(cut["data"])
+    assert "max_batch" in cut["engine"]
+    assert 0 < cut["recall_at_10_min"] <= 1
+    assert cut["why"]
+    # eigensystem_allocation needs the subspaces to fit the tiny width
+    file = next(c["file"] for c in spec.load_benchmark()["configs"]
+                if c["name"] == config)
+    taco = dict(json.loads((bench_tiny.ROOT / file).read_text())["taco"],
+                **cut["taco"])
+    assert taco["n_subspaces"] * taco["subspace_dim"] <= cut["data"]["d"]
 
 
 def test_every_declared_metric_has_a_reader():
